@@ -8,11 +8,17 @@ to fabricate 512 host devices; tests and benches must keep seeing 1 device.
 from __future__ import annotations
 
 
+# Both meshes use Auto axes: ``jax.make_mesh`` defaults to Explicit axes,
+# under which the model's unannotated gathers (the embedding lookup) raise
+# ``ShardingTypeError`` instead of letting the partitioner place them.
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1, data: int = 1):
@@ -20,7 +26,8 @@ def make_local_mesh(model: int = 1, data: int = 1):
     import jax
     n = len(jax.devices())
     assert model * data <= n, f"need {model * data} devices, have {n}"
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants (roofline denominators)

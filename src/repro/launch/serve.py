@@ -1,16 +1,17 @@
 """OpenAI-compatible streaming HTTP front end over the NALAR engine pool.
 
-    PYTHONPATH=src python -m repro.launch.serve --port 8080 --replicas 2
+    PYTHONPATH=src python -m repro.launch.serve --port 8080
     curl -N localhost:8080/v1/chat/completions -d '{
         "model": "nalar-llm", "stream": true,
         "messages": [{"role": "user", "content": "hello there"}]}'
 
 The launcher builds the pooled runtime (``build_pool_runtime``: N real
-``InferenceEngine`` replicas behind one ``llm`` agent type) and serves
-``/v1/chat/completions`` on stdlib ``http.server`` threads — no new
-dependency.  ``"stream": true`` answers with Server-Sent Events riding the
-token-streaming data plane: the engine step loop emits per-slot chunks,
-the bridge appends them to the request's future, and the handler forwards
+``InferenceEngine`` replicas behind one ``llm`` agent type, at the arch's
+published width with random weights) and serves ``/v1/chat/completions``
+on stdlib ``http.server`` threads — no new dependency.  ``"stream": true``
+answers with Server-Sent Events riding the token-streaming data plane:
+the engine step loop emits per-slot chunks, the bridge appends them to
+the request's future, and the handler forwards
 each increment the moment ``Future.wait_streamed`` wakes.  The delta loop
 tracks how many tokens it has already sent, so a mid-stream retry (which
 truncates the chunk log back to the attempt boundary and re-streams) never
@@ -45,6 +46,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, List, Optional
 
 from ..workloads.router import build_pool_runtime
+from .compile_cache import use_compile_cache
 
 #: required envelope keys, checked at publish time (SNIPPETS §3: validate
 #: where events are produced so schema drift cannot reach consumers)
@@ -320,24 +322,25 @@ def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8080)
-    p.add_argument("--replicas", type=int, default=2)
+    p.add_argument("--replicas", type=int, default=1)
     p.add_argument("--arch", default="qwen3_0_6b")
-    p.add_argument("--max-batch", type=int, default=4)
-    p.add_argument("--max-seq", type=int, default=128)
+    p.add_argument("--max-batch", type=int, default=8)
+    p.add_argument("--max-seq", type=int, default=2048)
     p.add_argument("--max-new", type=int, default=32,
                    help="default max_tokens when the request omits it")
     p.add_argument("--selftest", action="store_true",
                    help="ephemeral-port endpoint + real-client assertions "
-                        "(the CI streaming-smoke job)")
+                        "on the smoke config (the CI streaming-smoke job)")
     args = p.parse_args()
 
+    use_compile_cache()
     if args.selftest:
         selftest(replicas=args.replicas, max_new=min(args.max_new, 24))
         return
 
     rt = build_pool_runtime(replicas=args.replicas, arch=args.arch,
                             max_batch=args.max_batch, max_seq=args.max_seq,
-                            max_new_tokens=args.max_new)
+                            max_new_tokens=args.max_new, smoke=False)
     rt.start()
     fe = OpenAIFrontend(rt, default_max_tokens=args.max_new)
     server = fe.serve(host=args.host, port=args.port)

@@ -144,6 +144,39 @@ def get_slot(cache: dict, slot: int) -> dict:
     return out
 
 
+def default_pool_pages(max_batch: int, max_seq: int, page_size: int) -> int:
+    """KV pool size when the caller gives none: two full contexts' worth of
+    pages per batch slot (room for resident sessions between turns)."""
+    return max_batch * (max_seq // page_size + 1) * 2
+
+
+def paged_step_fn(model: Model, max_seq: int, kernel: bool,
+                  all_rows: bool = False) -> Callable:
+    """The paged plane's fused step, unjitted: chunked prefill + decode +
+    per-slot sampling prep in one function over (params, tokens [B,T],
+    valid [B], slim cache, k pages, v pages, page table [B,max_pages]).
+
+    Returns (logits, greedy, slim cache, k pages, v pages).  By default
+    ``logits`` is each slot's next-token row [B,V] (taken at its last valid
+    position) and ``greedy`` its argmax [B]: the [B,T,V] logits never
+    leave the device.  ``all_rows=True`` (the speculative verifier, which
+    needs every position) returns the full [B,T,V] logits and the
+    per-position argmax [B,T]."""
+    paged_fn = model.decode_chunk_paged
+
+    def step(params, toks, valid, cache, kp, vp, pt):
+        logits, cache, kp, vp = paged_fn(params, toks, valid, cache, kp, vp,
+                                         pt, max_seq=max_seq, kernel=kernel)
+        if all_rows:
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, greedy, cache, kp, vp
+        rows = jnp.take_along_axis(
+            logits, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)[:, 0]
+        return rows, jnp.argmax(rows, axis=-1), cache, kp, vp
+
+    return step
+
+
 class InferenceEngine:
     def __init__(self, model: Model, params: dict, *, max_batch: int = 8,
                  max_seq: int = 512, instance_id: str = "engine:0",
@@ -190,7 +223,6 @@ class InferenceEngine:
 
         # per-slot state
         self.slots: List[Optional[Request]] = [None] * max_batch
-        self.cache = model.init_cache(max_batch, max_seq)
         self._active_mask = np.zeros(max_batch, bool)
         # slot -> prompt tokens not yet consumed (resumed suffixes and
         # chunked prefills); always cleared when the slot is vacated
@@ -206,7 +238,8 @@ class InferenceEngine:
         elif self.cfg.family == "hybrid":
             self.pool = StateCachePool(self.cfg)
         else:
-            n_pages = pool_pages or (max_batch * (max_seq // page_size + 1) * 2)
+            n_pages = pool_pages or default_pool_pages(max_batch, max_seq,
+                                                       page_size)
             self.pool = PagedKVPool(self.cfg, n_pages=n_pages,
                                     page_size=page_size)
         if kv_registry is not None:
@@ -247,9 +280,16 @@ class InferenceEngine:
         # anonymous requests get a synthetic key released at vacate)
         self._slot_sid: Dict[int, str] = {}
         if self._paged:
-            self.cache = {key: v for key, v in self.cache.items()
+            # slim cache: every leaf but the dense k/v, which are never
+            # allocated (at published widths they would cost as much HBM as
+            # the pool itself)
+            shapes = model.cache_shapes(max_batch, max_seq)
+            self.cache = {key: jnp.zeros(s.shape, s.dtype)
+                          for key, s in shapes.items()
                           if key not in ("k", "v")}
             self._max_pages = self.pool.pages_needed(self.max_seq)
+        else:
+            self.cache = model.init_cache(max_batch, max_seq)
         # slot -> token ids whose K/V occupy the slot's cache positions so
         # far (None = unknown provenance, the finish write stays opaque)
         self._slot_tokens: Dict[int, Optional[List[int]]] = {}
@@ -281,25 +321,13 @@ class InferenceEngine:
         # tables).  Only the [B,V] next-token rows and the greedy argmax
         # cross the host boundary — the [B,T,V] logits never leave device.
         self._paged_step: Optional[Callable] = None
+        # donate the pool arrays on TPU so the step updates them in place
+        # (CPU donation is a no-op and only warns)
+        donate = (4, 5) if jax.default_backend() == "tpu" else ()
         if self._paged:
-            paged_fn = model.decode_chunk_paged
-            _max_seq = self.max_seq
-            _kernel = self._paged_kernel
-
-            def _paged_chunk(params, toks, valid, cache, kp, vp, pt):
-                logits, cache, kp, vp = paged_fn(
-                    params, toks, valid, cache, kp, vp, pt,
-                    max_seq=_max_seq, kernel=_kernel)
-                rows = jnp.take_along_axis(
-                    logits, jnp.maximum(valid - 1, 0)[:, None, None],
-                    axis=1)[:, 0]                               # [B,V]
-                greedy = jnp.argmax(rows, axis=-1)
-                return rows, greedy, cache, kp, vp
-
-            # donate the pool arrays on TPU so the step updates them in
-            # place (CPU donation is a no-op and only warns)
-            donate = (4, 5) if jax.default_backend() == "tpu" else ()
-            self._paged_step = jax.jit(_paged_chunk, donate_argnums=donate)
+            self._paged_step = jax.jit(
+                paged_step_fn(model, self.max_seq, self._paged_kernel),
+                donate_argnums=donate)
 
         # speculative decoding (paged plane only): a small-tier draft
         # proposes spec_k tokens per decode round; the same paged chunk
@@ -327,20 +355,10 @@ class InferenceEngine:
             from .speculative import DraftEngine
             self._spec = DraftEngine(draft_model, draft_params,
                                      max_batch=max_batch, max_seq=max_seq)
-            paged_fn = model.decode_chunk_paged
-            _max_seq = self.max_seq
-            _kernel = self._paged_kernel
-
-            def _paged_chunk_all(params, toks, valid, cache, kp, vp, pt):
-                logits, cache, kp, vp = paged_fn(
-                    params, toks, valid, cache, kp, vp, pt,
-                    max_seq=_max_seq, kernel=_kernel)
-                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return logits, greedy, cache, kp, vp
-
-            donate = (4, 5) if jax.default_backend() == "tpu" else ()
-            self._paged_step_all = jax.jit(_paged_chunk_all,
-                                           donate_argnums=donate)
+            self._paged_step_all = jax.jit(
+                paged_step_fn(model, self.max_seq, self._paged_kernel,
+                              all_rows=True),
+                donate_argnums=donate)
         # lazily jitted encoder pass for chunked encoder-decoder admission
         self._encode_cross: Optional[Callable] = None
         self._prefill_cache: Dict[int, Callable] = {}
@@ -1120,22 +1138,22 @@ class InferenceEngine:
             if valid[i]:
                 pt[i] = self.pool.page_table(self._slot_sid[i],
                                              self._max_pages)
+        step = self._paged_step_all or self._paged_step
+        args = (self.params, jnp.asarray(toks), jnp.asarray(valid),
+                self.cache)
+
+        def run(kp, vp):
+            out, greedy, cache, kp, vp = step(*args, kp, vp, jnp.asarray(pt))
+            return (out, greedy, cache), kp, vp
+
+        out, greedy, self.cache = self.pool.update_pages(run)
         if self._paged_step_all is not None:
-            logits, greedy_all, self.cache, self.pool.k, self.pool.v = \
-                self._paged_step_all(self.params, jnp.asarray(toks),
-                                     jnp.asarray(valid), self.cache,
-                                     self.pool.k, self.pool.v,
-                                     jnp.asarray(pt))
-            greedy_np_all = np.asarray(greedy_all)               # [B,T]
+            logits, greedy_np_all = out, np.asarray(greedy)      # [B,T]
             greedy = greedy_np_all[np.arange(self.max_batch),
                                    np.maximum(valid - 1, 0)]     # [B]
             rows = None                                          # lazy [B,V]
         else:
-            rows, greedy, self.cache, self.pool.k, self.pool.v = \
-                self._paged_step(self.params, jnp.asarray(toks),
-                                 jnp.asarray(valid), self.cache,
-                                 self.pool.k, self.pool.v, jnp.asarray(pt))
-            logits = greedy_np_all = None
+            rows, logits, greedy_np_all = out, None, None
         self.metrics.decode_steps += 1
         for i in active:
             if valid[i] and i not in spec_plan:

@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -475,6 +475,20 @@ class PagedKVPool:
                 and self.k.shape[2:] == other.k.shape[2:]
                 and self.k.dtype == other.k.dtype)
 
+    def update_pages(self, fn: Callable[[Any, Any], Tuple[Any, Any, Any]]):
+        """Run ``fn(k, v) -> (out, k, v)``, install the returned pages and
+        return ``out``, atomically with every other reader and writer.
+
+        The engine's paged step donates the page arrays on TPU, so the
+        arrays it was handed are deleted once it is enqueued.  Holding the
+        lock from that read to the write-back means a migration export or
+        import on another thread sees either the old pages or the step's
+        result: never a deleted array, and never a write the step's result
+        then overwrites."""
+        with self._lock:
+            out, self.k, self.v = fn(self.k, self.v)
+        return out
+
     def export_session(self, session_id: str) -> Optional[Dict[str, Any]]:
         """Serialize a session's K/V pages (the migration payload)."""
         with self._lock:
@@ -577,9 +591,9 @@ class PagedKVPool:
                 return None
             idx = jnp.asarray(sp.pages)
             tokens = sp.tokens
-        L = self.cfg.n_layers
-        k = self.k[:, idx].reshape(L, -1, *self.k.shape[3:])[:, :max_seq]
-        v = self.v[:, idx].reshape(L, -1, *self.v.shape[3:])[:, :max_seq]
+            L = self.cfg.n_layers
+            k = self.k[:, idx].reshape(L, -1, *self.k.shape[3:])[:, :max_seq]
+            v = self.v[:, idx].reshape(L, -1, *self.v.shape[3:])[:, :max_seq]
         return k, v, tokens
 
     # ------------------------------------------------------------- writing

@@ -123,7 +123,8 @@ def build_pool_runtime(*, replicas: int = 3, arch: str = "qwen3_0_6b",
                        prefix_sharing: bool = True,
                        tiers=None, tier_archs=None,
                        draft_layers: int = 0, spec_k: int = 3,
-                       decode=None, seed: int = 0) -> NalarRuntime:
+                       decode=None, seed: int = 0,
+                       smoke: bool = True) -> NalarRuntime:
     """One ``llm`` agent type backed by an ``EnginePool`` of real replicas.
 
     This is the pooled topology of the migration/routing benchmarks: N
@@ -156,10 +157,14 @@ def build_pool_runtime(*, replicas: int = 3, arch: str = "qwen3_0_6b",
     small-tier replicas.  ``draft_layers > 0`` arms every replica whose
     model has more layers than that with a layer-truncated self-draft
     (speculative decoding, ``spec_k`` proposals per round).
+
+    ``smoke=False`` builds every replica's model at its published width
+    (``get_config``) instead of the reduced smoke variant; weights are
+    random, drawn from ``seed`` either way.
     """
     import jax
 
-    from ..configs import get_smoke_config
+    from ..configs import get_config, get_smoke_config
     from ..models import build_model
     from ..serving import InferenceEngine, SamplingParams
     from ..serving.pool import register_engine_pool
@@ -174,7 +179,7 @@ def build_pool_runtime(*, replicas: int = 3, arch: str = "qwen3_0_6b",
 
     def _built(a):
         if a not in built:
-            c = get_smoke_config(a)
+            c = get_smoke_config(a) if smoke else get_config(a)
             m = build_model(c)
             built[a] = (m, m.init(jax.random.PRNGKey(seed)))
         return built[a]

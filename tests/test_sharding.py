@@ -27,7 +27,8 @@ def test_param_specs_divisibility_rules():
         from repro.distributed.sharding import ShardingRules, axis_size
         from repro.models import build_model
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for arch in ARCH_IDS:
             cfg = get_config(arch)
             model = build_model(cfg)
@@ -61,7 +62,8 @@ def test_smoke_config_lowers_and_compiles_on_mini_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.launch.dryrun import lower_combo
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for arch, shape in [("qwen3-0.6b", "train_4k"),
                             ("granite-moe-1b-a400m", "prefill_32k"),
                             ("mamba2-130m", "decode_32k"),
