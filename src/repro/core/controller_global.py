@@ -2,7 +2,7 @@
 
 Single-threaded, push-based loop.  Each period it:
  1. aggregates metrics + future-metadata mirrors from every node store
-    (modelled per-node fetch latency — this is what Fig. 10 measures),
+    (this is what Fig. 10 measures),
  2. runs the operator's policy program over the ClusterView,
  3. writes the resulting decisions (routing tables, priorities, migrations,
     provisioning) back into node stores, where component controllers consume
@@ -24,9 +24,10 @@ from aspiration to measured.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
-from collections import deque
+from contextlib import nullcontext
 from typing import Dict, Optional, Tuple
 
 from .policy import ActionSink, ClusterView, Policy, RetryPolicy
@@ -35,16 +36,20 @@ from .policy import ActionSink, ClusterView, Policy, RetryPolicy
 VIEW_PREFIXES = ("metrics:", "future:")
 
 
+def _host_span(name: str):
+    """A host span on the profiler's clock.  The core never imports JAX:
+    without JAX loaded no profiler session can be active, so no span."""
+    jax = sys.modules.get("jax")
+    return (jax.profiler.TraceAnnotation(name) if jax is not None
+            else nullcontext())
+
+
 class GlobalController:
     def __init__(self, runtime, policy: Policy, interval: float = 0.25,
-                 node_fetch_latency: float = 0.0,
                  full_rebuild_interval: int = 64) -> None:
         self.runtime = runtime
         self.policy = policy
         self.interval = interval
-        # virtual-time cost to poll one node's store (network RTT model);
-        # real wall-clock compute cost is measured separately for Fig. 10.
-        self.node_fetch_latency = node_fetch_latency
         # always-on rung 2 of the retry ladder: decides the fate of failures
         # component controllers escalated (reroute to a survivor / give up).
         # Swappable like the main policy, but kept separate from it so
@@ -60,10 +65,6 @@ class GlobalController:
         # escalation nudge fires on a timer thread and must not interleave
         # with a periodic tick now that the view is persistent shared state
         self._round_lock = threading.RLock()
-        # rolling histories (bounded: the loop ticks forever in long-lived
-        # deployments; Telemetry.control_rounds keeps the canonical record)
-        self.loop_wall_times: "deque[float]" = deque(maxlen=4096)
-        self.loop_breakdown: "deque[Dict[str, float]]" = deque(maxlen=4096)
         # incremental-collection state
         self._view: Optional[ClusterView] = None
         self._cursors: Dict[Tuple[str, str], int] = {}  # (node, prefix) -> seq
@@ -224,21 +225,19 @@ class GlobalController:
             return self._run_once_locked()
 
     def _run_once_locked(self) -> Dict[str, float]:
-        t0 = time.perf_counter()
-        rebuilds_before = self.rebuild_rounds
-        view = self.collect_view()
-        t1 = time.perf_counter()
-        sink = ActionSink()
-        self.policy.step(view, sink)
-        if view.escalated:
-            self.retry_policy.step(view, sink)
-        t2 = time.perf_counter()
-        self.apply(sink)
-        t3 = time.perf_counter()
-        # model the per-node fetch RTT in virtual time
-        if self.node_fetch_latency:
-            pass  # accounted by the benchmark harness, not the fast path
-        breakdown = {
+        with _host_span("nalar.runtime.control_round"):
+            t0 = time.perf_counter()
+            rebuilds_before = self.rebuild_rounds
+            view = self.collect_view()
+            t1 = time.perf_counter()
+            sink = ActionSink()
+            self.policy.step(view, sink)
+            if view.escalated:
+                self.retry_policy.step(view, sink)
+            t2 = time.perf_counter()
+            self.apply(sink)
+            t3 = time.perf_counter()
+        return {
             "collect": t1 - t0,
             "policy": t2 - t1,
             "push": t3 - t2,
@@ -250,13 +249,6 @@ class GlobalController:
             "n_collected": float(self._last_collected),
             "rebuild": float(self.rebuild_rounds > rebuilds_before),
         }
-        self.loop_wall_times.append(breakdown["total"])
-        self.loop_breakdown.append(breakdown)
-        self.runtime.telemetry.on_control_round(
-            view.now, breakdown["collect"], breakdown["policy"],
-            breakdown["push"], int(self._last_collected),
-            self.rebuild_rounds > rebuilds_before)
-        return breakdown
 
     # ----------------------------------------------------------- enforcement
     def apply(self, sink: ActionSink) -> None:

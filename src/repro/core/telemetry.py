@@ -9,7 +9,6 @@ to compute the latency distributions of Fig. 9.
 from __future__ import annotations
 
 import threading
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -78,33 +77,11 @@ class MigrationRecord:
     at: float
 
 
-@dataclass
-class ControlRoundRecord:
-    """One global-controller round: wall-clock breakdown plus how much state
-    actually moved (``n_collected`` — the churn a delta round paid for) and
-    whether the round was a full view rebuild (bootstrap / escape hatch)."""
-
-    at: float                 # virtual time of the round
-    collect: float            # wall-clock seconds
-    policy: float
-    push: float
-    n_collected: int
-    rebuild: bool
-
-    @property
-    def total(self) -> float:
-        return self.collect + self.policy + self.push
-
-
 class Telemetry:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self.requests: Dict[str, RequestRecord] = {}
         self.migrations: List[MigrationRecord] = []
-        # bounded: a long-lived deployment ticks forever (interval=0.25s ->
-        # ~345K rounds/day); keep a rolling window, like the FutureTable
-        # keeps the deployment memory-flat
-        self.control_rounds: "deque[ControlRoundRecord]" = deque(maxlen=4096)
         self.futures_done = 0
 
     def start_request(self, request_id: str, session_id: str, now: float,
@@ -174,29 +151,6 @@ class Telemetry:
     def on_migration(self, fut, src: str, dst: str, now: float) -> None:
         with self._lock:
             self.migrations.append(MigrationRecord(fut.fid, src, dst, now))
-
-    def on_control_round(self, at: float, collect: float, policy: float,
-                         push: float, n_collected: int,
-                         rebuild: bool) -> None:
-        with self._lock:
-            self.control_rounds.append(ControlRoundRecord(
-                at, collect, policy, push, n_collected, rebuild))
-
-    def control_summary(self) -> Dict[str, float]:
-        """Mean per-stage wall-clock of the control loop (Fig. 10 shape)."""
-        with self._lock:
-            rounds = list(self.control_rounds)
-        if not rounds:
-            return {"rounds": 0}
-        n = len(rounds)
-        return {
-            "rounds": n,
-            "rebuilds": sum(r.rebuild for r in rounds),
-            "collect_ms": 1e3 * sum(r.collect for r in rounds) / n,
-            "policy_ms": 1e3 * sum(r.policy for r in rounds) / n,
-            "push_ms": 1e3 * sum(r.push for r in rounds) / n,
-            "mean_collected": sum(r.n_collected for r in rounds) / n,
-        }
 
     # ------------------------------------------------------------- analysis
     def completed_latencies(self) -> List[float]:

@@ -139,6 +139,7 @@ def decode_ring(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, n_rep, D), q.dtype),
         interpret=interpret,
+        name="decode_ring",
     )(pos.astype(jnp.int32), qg, cache_k, cache_v)
     return out.reshape(B, 1, H, D)
 
@@ -220,6 +221,7 @@ def paged_decode_chunk(q: jnp.ndarray, k_pages: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_chunk",
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
       qg, k_pages, v_pages)
     return (out.reshape(B, Hkv, T, n_rep, D)

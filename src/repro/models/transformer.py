@@ -285,35 +285,42 @@ def decode_chunk_paged(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         # so the attention HLO (and its reduction order) is identical
         return pages[pt_c].reshape(B, maxp * P, Hkv, Dh)[:, :C]
 
+    def write(kp, vp, k, v):
+        with jax.named_scope("kv_write"):
+            return (kp.at[widx, off].set(k.astype(kp.dtype), mode="drop"),
+                    vp.at[widx, off].set(v.astype(vp.dtype), mode="drop"))
+
+    # named scopes (attention, attention/kv_write, mlp, unembed) label the
+    # step's ops in a device profile
     def step(carry, xs):
         x = carry
         layer_p, kp, vp = xs
-        h = L.apply_norm(x, layer_p["attn_norm"], cfg)
-        q, k, v = L.attention_qkv(h, layer_p["attn"], cfg, positions)
-        if kernel:
-            kp = kp.at[widx, off].set(k.astype(kp.dtype), mode="drop")
-            vp = vp.at[widx, off].set(v.astype(vp.dtype), mode="drop")
-            o = L.paged_chunk_attention(q, kp, vp, page_table, pos, cfg)
-        elif W:
-            # mirror decode_chunk's order exactly: attend the pre-write
-            # view + the chunk itself, then write
-            o = L.chunk_decode_attention_windowed(
-                q, gather(kp), gather(vp), k, v, pos, valid_len, positions,
-                cfg, window=W)
-            kp = kp.at[widx, off].set(k.astype(kp.dtype), mode="drop")
-            vp = vp.at[widx, off].set(v.astype(vp.dtype), mode="drop")
-        else:
-            kp = kp.at[widx, off].set(k.astype(kp.dtype), mode="drop")
-            vp = vp.at[widx, off].set(v.astype(vp.dtype), mode="drop")
-            o = L.chunk_decode_attention(q, gather(kp), gather(vp),
-                                         positions, cfg)
-        x = x + jnp.einsum("bshk,hkd->bsd", o, layer_p["attn"]["wo"])
-        h = L.apply_norm(x, layer_p["mlp_norm"], cfg)
-        y, _aux = _ffn(h, layer_p, cfg, moe_impl)
-        x = x + y
+        with jax.named_scope("attention"):
+            h = L.apply_norm(x, layer_p["attn_norm"], cfg)
+            q, k, v = L.attention_qkv(h, layer_p["attn"], cfg, positions)
+            if kernel:
+                kp, vp = write(kp, vp, k, v)
+                o = L.paged_chunk_attention(q, kp, vp, page_table, pos, cfg)
+            elif W:
+                # mirror decode_chunk's order exactly: attend the pre-write
+                # view + the chunk itself, then write
+                o = L.chunk_decode_attention_windowed(
+                    q, gather(kp), gather(vp), k, v, pos, valid_len,
+                    positions, cfg, window=W)
+                kp, vp = write(kp, vp, k, v)
+            else:
+                kp, vp = write(kp, vp, k, v)
+                o = L.chunk_decode_attention(q, gather(kp), gather(vp),
+                                             positions, cfg)
+            x = x + jnp.einsum("bshk,hkd->bsd", o, layer_p["attn"]["wo"])
+        with jax.named_scope("mlp"):
+            h = L.apply_norm(x, layer_p["mlp_norm"], cfg)
+            y, _aux = _ffn(h, layer_p, cfg, moe_impl)
+            x = x + y
         return x, (kp, vp)
 
     x, (ks, vs) = jax.lax.scan(step, x, (params["layers"], k_pages, v_pages))
-    x = L.apply_norm(x, params["final_norm"], cfg)
-    logits = L.unembed(x, params["embed"], cfg)                # [B,T,V]
+    with jax.named_scope("unembed"):
+        x = L.apply_norm(x, params["final_norm"], cfg)
+        logits = L.unembed(x, params["embed"], cfg)            # [B,T,V]
     return logits, {"pos": pos + valid_len}, ks, vs

@@ -75,9 +75,14 @@ class Request:
     expired: bool = False
     # wall-clock (time.monotonic) stamps taken by the engine itself, so TTFT
     # is measured on one clock regardless of which kernel created the request
+    # and splits into the wait for a slot (admitted_at - submitted_wall) and
+    # prefill (first_token_at - admitted_at)
     submitted_wall: float = -1.0
+    admitted_at: float = -1.0
     first_token_at: float = -1.0
     finished_at: float = -1.0
+    # engine steps in which this request's slot fed prompt tokens
+    prefill_steps: int = 0
     prefix_reused_tokens: int = 0
     # execution path the engine admitted this request onto ("paged" /
     # "fused" / "masked") — observability for tests and benchmarks that

@@ -5,7 +5,10 @@ agent serving into an actual agent-serving system: a stub call on an
 engine-backed agent creates an ordinary NALAR future, the runtime routes it
 like any other, and the component controller hands it here — where it becomes
 a ``serving.Request`` in the engine's continuous-batching queue.  A pump
-thread steps the engine; completion callbacks resolve the futures.
+thread steps the engine; completion callbacks resolve the futures.  Host
+spans on the profiler's clock: ``nalar.bridge.submit`` (a future becomes a
+request, on the caller's thread) and ``nalar.bridge.deliver`` (chunk and
+completion callbacks into futures, on the pump thread after each step).
 
 Per-session KV state flows through the two core registries:
 
@@ -32,9 +35,11 @@ import time
 import zlib
 from collections import deque
 from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core.directives import Directives
 from ..core.executor import EngineBackedMethod
@@ -72,6 +77,14 @@ class GenerationResult:
     prompt_tokens: int              # tokens actually sent this call
     prefix_reused_tokens: int       # prefix restored from the session cache
     engine_id: str = ""
+    # the engine's stamps (time.monotonic; -1 = never reached) and the
+    # steps that fed this call's prompt: time to first token splits into
+    # the wait for a slot and prefill
+    submitted_wall: float = -1.0
+    admitted_at: float = -1.0
+    first_token_at: float = -1.0
+    finished_at: float = -1.0
+    prefill_steps: int = 0
 
     def __len__(self) -> int:
         return len(self.tokens)
@@ -309,6 +322,7 @@ class EngineBridge:
             except BaseException as e:  # noqa: BLE001 — fail this call only
                 controller.complete_async(fut, error=e)
 
+    @partial(annotate_function, name="nalar.bridge.submit")
     def _submit_now(self, fut: Future, controller,
                     method: "EngineMethod") -> None:
         args, kwargs = resolve_args(
@@ -482,7 +496,8 @@ class EngineBridge:
                     return
             try:
                 self.engine.step()
-                self.engine.drain_completions()
+                with TraceAnnotation("nalar.bridge.deliver"):
+                    self.engine.drain_completions()
             except BaseException as e:  # noqa: BLE001 — engine died
                 self.fail_inflight(e)
 
@@ -577,7 +592,9 @@ class EngineMethod(EngineBackedMethod):
             request_id=req.request_id, session_id=req.session_id,
             tokens=list(req.generated), prompt_tokens=len(req.prompt),
             prefix_reused_tokens=req.prefix_reused_tokens,
-            engine_id=engine_id)
+            engine_id=engine_id, submitted_wall=req.submitted_wall,
+            admitted_at=req.admitted_at, first_token_at=req.first_token_at,
+            finished_at=req.finished_at, prefill_steps=req.prefill_steps)
 
 
 def register_engine_agent(runtime, name: str, engine: InferenceEngine, *,

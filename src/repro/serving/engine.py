@@ -30,6 +30,15 @@ Execution model:
     shared), so admission, resume, eviction and finish move zero cache
     bytes and the dense per-slot K/V arrays are never allocated.
     ``paged_decode=False`` restores the dense gather/write-back path.
+
+Host spans: ``step`` and its phases run inside ``jax.profiler`` trace
+annotations with fixed names (``nalar.engine.step`` holding
+``nalar.engine.admit``, ``.prepare``, ``nalar.kv.append``,
+``nalar.engine.dispatch``, ``.device_wait``, ``nalar.kv.commit``,
+``nalar.engine.sample`` and ``.emit``), on the profiler's clock, so a
+device trace says which host work each idle gap sat in.  They record only
+while a profiler session is active.  ``device_wait`` holds nothing but the
+step's first host read of its outputs: outside it, the step's host time.
 """
 
 from __future__ import annotations
@@ -38,11 +47,13 @@ import threading
 import time
 import zlib
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..configs.base import ModelConfig
 from ..models.model import Model
@@ -621,6 +632,7 @@ class InferenceEngine:
         self._req_rng[req.request_id] = key
         return int(np.asarray(sample(logits[row:row + 1], sp, sub))[0])
 
+    @partial(annotate_function, name="nalar.engine.admit")
     def _admit(self) -> None:
         for slot in range(self.max_batch):
             if self._active_mask[slot]:
@@ -649,6 +661,7 @@ class InferenceEngine:
                 # would corrupt them.  Defer until that slot finishes.
                 self.queue.push(req)
                 return
+            req.admitted_at = now
             W = self.cfg.sliding_window
             if self._paged:
                 req.decode_path = "paged"
@@ -758,6 +771,7 @@ class InferenceEngine:
                 self.metrics.prefill_tokens += len(req.prompt)
             else:
                 logits, row_cache = self._prefill(req)
+                req.prefill_steps = 1
                 greedy = np.asarray(jnp.argmax(logits, axis=-1))
                 tok = self._sample_slot(req, logits, 0, greedy)
                 req.generated.append(tok)
@@ -983,6 +997,7 @@ class InferenceEngine:
         return tokens
 
     # ----------------------------------------------------------------- step
+    @partial(annotate_function, name="nalar.engine.step")
     def step(self) -> int:
         """Admit + one piggybacked batched step.
 
@@ -1013,7 +1028,10 @@ class InferenceEngine:
                 self.metrics.active = 0
                 return 0
             pending = self._pending_prompt
-            prefilling = any(pending.get(i) for i in active)
+            prefilling = [i for i in active if pending.get(i)]
+            for i in prefilling:
+                # every plane feeds each such slot at least one prompt token
+                self.slots[i].prefill_steps += 1
             budget = max(1, self.prefill_chunk) if prefilling else 1
             if self._paged:
                 sampled = self._step_paged(active, budget)
@@ -1021,23 +1039,25 @@ class InferenceEngine:
                 sampled = self._step_fused(active, budget)
             else:
                 sampled = self._step_masked(active, budget)
-            pos_arr = np.asarray(self.cache["pos"])
-            now = time.monotonic()
-            for i in active:
-                req = self.slots[i]
-                if req is None:
-                    continue
-                done = False
-                if i in sampled:
-                    tok = req.generated[-1]
-                    done = (len(req.generated) >= req.sampling.max_new_tokens
-                            or tok == req.sampling.eos_token)
-                if pos_arr[i] >= self.max_seq - 1:
-                    done = True
-                if done:
-                    self._finish_slot(i, now)
-                else:
-                    self._emit_stream(req)
+            with TraceAnnotation("nalar.engine.emit"):
+                pos_arr = np.asarray(self.cache["pos"])
+                now = time.monotonic()
+                for i in active:
+                    req = self.slots[i]
+                    if req is None:
+                        continue
+                    done = False
+                    if i in sampled:
+                        tok = req.generated[-1]
+                        done = (len(req.generated)
+                                >= req.sampling.max_new_tokens
+                                or tok == req.sampling.eos_token)
+                    if pos_arr[i] >= self.max_seq - 1:
+                        done = True
+                    if done:
+                        self._finish_slot(i, now)
+                    else:
+                        self._emit_stream(req)
             self.metrics.queued = len(self.queue)
             self.metrics.active = int(self._active_mask.sum())
             return len(active)
@@ -1069,24 +1089,113 @@ class InferenceEngine:
         index).  A slot whose reservation fails is aborted explicitly —
         counted, finished with what it has — never silently wedged."""
         pending = self._pending_prompt
-        pos_before = np.asarray(self.cache["pos"])
-        # plan speculation: decode-only slots with a shadowing draft stream
-        # propose spec_k tokens each (batched across slots in the draft)
-        spec_plan: Dict[int, List[int]] = {}
-        if self._spec is not None:
-            want: Dict[int, int] = {}
+        with TraceAnnotation("nalar.engine.prepare"):
+            pos_before = np.asarray(self.cache["pos"])
+            spec_plan = self._plan_speculation(active, pos_before)
+            toks, valid = self._feed(active, budget, spec_plan)
+        now = time.monotonic()
+        aborted: List[int] = []
+        with TraceAnnotation("nalar.kv.append"):
             for i in active:
-                if pending.get(i) or i not in self._spec_ok:
+                if not valid[i]:
                     continue
+                if not self.pool.begin_append(self._slot_sid[i],
+                                              int(valid[i]), now):
+                    self.metrics.paged_append_failures += 1
+                    valid[i] = 0
+                    aborted.append(i)
+            if self._prefix_share_ok:
+                for i in active:
+                    ids = self._slot_tokens.get(i)
+                    if ids is not None and valid[i] and i not in spec_plan:
+                        ids.extend(int(t) for t in toks[i, :valid[i]])
+            pt = np.full((self.max_batch, self._max_pages), -1, np.int32)
+            for i in active:
+                if valid[i]:
+                    pt[i] = self.pool.page_table(self._slot_sid[i],
+                                                 self._max_pages)
+        step = self._paged_step_all or self._paged_step
+        with TraceAnnotation("nalar.engine.dispatch"):
+            args = (self.params, jnp.asarray(toks), jnp.asarray(valid),
+                    self.cache)
+
+            def run(kp, vp):
+                out, greedy, cache, kp, vp = step(*args, kp, vp,
+                                                  jnp.asarray(pt))
+                return (out, greedy, cache), kp, vp
+
+            out, greedy, self.cache = self.pool.update_pages(run)
+        self.metrics.decode_steps += 1
+        with TraceAnnotation("nalar.kv.commit"):
+            # host bookkeeping while the device runs the step
+            for i in active:
+                if valid[i] and i not in spec_plan:
+                    n = int(valid[i])
+                    ids = (toks[i, :n].tolist()
+                           if self._slot_tokens.get(i) is not None else None)
+                    self.pool.commit_append(self._slot_sid[i], n,
+                                            token_ids=ids, now=now)
+            for i in aborted:
+                self._finish_slot(i, now)
+        with TraceAnnotation("nalar.engine.device_wait"):
+            greedy_host = np.asarray(greedy)
+        if self._paged_step_all is not None:
+            logits, greedy_np_all = out, greedy_host                # [B,T]
+            greedy_np = greedy_host[np.arange(self.max_batch),
+                                    np.maximum(valid - 1, 0)]       # [B]
+            rows = None                                             # lazy
+        else:
+            rows, logits, greedy_np_all = out, None, None
+            greedy_np = greedy_host
+        ready = [i for i in active if valid[i] and i not in pending]
+        sampled: set = set()
+        with TraceAnnotation("nalar.engine.sample"):
+            for i in ready:
                 req = self.slots[i]
-                if req is None or not req.generated:
+                if i in spec_plan:
+                    self._verify_slot(req, i, spec_plan[i], int(toks[i, 0]),
+                                      int(pos_before[i]), logits,
+                                      greedy_np_all, now)
+                    sampled.add(i)
                     continue
-                k_i = self._spec_budget(i, req, int(pos_before[i]))
-                if k_i > 0:
-                    self._spec.observe(i, [int(req.generated[-1])])
-                    want[i] = k_i
-            if want:
-                spec_plan = self._spec.propose(want)
+                if rows is None and logits is not None:
+                    rows = jnp.take_along_axis(
+                        logits, jnp.asarray(np.maximum(valid - 1, 0))
+                        [:, None, None], axis=1)[:, 0]              # [B,V]
+                tok = self._sample_slot(req, rows, i, greedy_np)
+                req.generated.append(tok)
+                if req.first_token_at < 0:
+                    req.first_token_at = time.monotonic()
+                self.metrics.tokens_generated += 1
+                sampled.add(i)
+        return sampled
+
+    def _plan_speculation(self, active: List[int],
+                          pos_before: np.ndarray) -> Dict[int, List[int]]:
+        """Decode-only slots with a shadowing draft stream propose
+        ``spec_k`` tokens each (batched across slots in the draft)."""
+        if self._spec is None:
+            return {}
+        want: Dict[int, int] = {}
+        for i in active:
+            if self._pending_prompt.get(i) or i not in self._spec_ok:
+                continue
+            req = self.slots[i]
+            if req is None or not req.generated:
+                continue
+            k_i = self._spec_budget(i, req, int(pos_before[i]))
+            if k_i > 0:
+                self._spec.observe(i, [int(req.generated[-1])])
+                want[i] = k_i
+        return self._spec.propose(want) if want else {}
+
+    def _feed(self, active: List[int], budget: int,
+              spec_plan: Dict[int, List[int]]):
+        """The step's tokens [B,T] and valid counts [B]: a prompt chunk for
+        each prefilling slot (consumed from its pending prompt), the last
+        token plus any drafts for each decoding slot.  The width is sized
+        to need, rounded to a power of two."""
+        pending = self._pending_prompt
         need = 1
         for i in active:
             q = pending.get(i)
@@ -1118,76 +1227,7 @@ class InferenceEngine:
                 seq += spec_plan.get(i, [])
                 toks[i, :len(seq)] = seq
                 valid[i] = len(seq)
-        now = time.monotonic()
-        aborted: List[int] = []
-        for i in active:
-            if not valid[i]:
-                continue
-            if not self.pool.begin_append(self._slot_sid[i], int(valid[i]),
-                                          now):
-                self.metrics.paged_append_failures += 1
-                valid[i] = 0
-                aborted.append(i)
-        if self._prefix_share_ok:
-            for i in active:
-                ids = self._slot_tokens.get(i)
-                if ids is not None and valid[i] and i not in spec_plan:
-                    ids.extend(int(t) for t in toks[i, :valid[i]])
-        pt = np.full((self.max_batch, self._max_pages), -1, np.int32)
-        for i in active:
-            if valid[i]:
-                pt[i] = self.pool.page_table(self._slot_sid[i],
-                                             self._max_pages)
-        step = self._paged_step_all or self._paged_step
-        args = (self.params, jnp.asarray(toks), jnp.asarray(valid),
-                self.cache)
-
-        def run(kp, vp):
-            out, greedy, cache, kp, vp = step(*args, kp, vp, jnp.asarray(pt))
-            return (out, greedy, cache), kp, vp
-
-        out, greedy, self.cache = self.pool.update_pages(run)
-        if self._paged_step_all is not None:
-            logits, greedy_np_all = out, np.asarray(greedy)      # [B,T]
-            greedy = greedy_np_all[np.arange(self.max_batch),
-                                   np.maximum(valid - 1, 0)]     # [B]
-            rows = None                                          # lazy [B,V]
-        else:
-            rows, logits, greedy_np_all = out, None, None
-        self.metrics.decode_steps += 1
-        for i in active:
-            if valid[i] and i not in spec_plan:
-                n = int(valid[i])
-                ids = (toks[i, :n].tolist()
-                       if self._slot_tokens.get(i) is not None else None)
-                self.pool.commit_append(self._slot_sid[i], n, token_ids=ids,
-                                        now=now)
-        for i in aborted:
-            self._finish_slot(i, now)
-        ready = [i for i in active if valid[i] and i not in pending]
-        if not ready:
-            return set()
-        greedy_np = np.asarray(greedy)
-        sampled: set = set()
-        for i in ready:
-            req = self.slots[i]
-            if i in spec_plan:
-                self._verify_slot(req, i, spec_plan[i], int(toks[i, 0]),
-                                  int(pos_before[i]), logits, greedy_np_all,
-                                  now)
-                sampled.add(i)
-                continue
-            if rows is None and logits is not None:
-                rows = jnp.take_along_axis(
-                    logits, jnp.asarray(np.maximum(valid - 1, 0))
-                    [:, None, None], axis=1)[:, 0]               # [B,V]
-            tok = self._sample_slot(req, rows, i, greedy_np)
-            req.generated.append(tok)
-            if req.first_token_at < 0:
-                req.first_token_at = time.monotonic()
-            self.metrics.tokens_generated += 1
-            sampled.add(i)
-        return sampled
+        return toks, valid
 
     def _spec_budget(self, slot: int, req: Request, pos: int) -> int:
         """Draft tokens worth proposing for this slot this round (0 = run a
