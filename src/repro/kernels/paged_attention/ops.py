@@ -38,11 +38,13 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 def paged_decode_chunk_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  v_pages: jnp.ndarray,
                                  page_table: jnp.ndarray, pos: jnp.ndarray,
-                                 *, scale: float, n_rep: int) -> jnp.ndarray:
-    """Chunk-extended paged decode (q [B,T,H,D]) over the KV pool.
+                                 *, layer: int | jnp.ndarray, scale: float,
+                                 n_rep: int) -> jnp.ndarray:
+    """Chunk-extended paged decode (q [B,T,H,D]) over layer ``layer`` of
+    the stacked KV pool [L, n_pages, page, Hkv, D].
 
     Not jitted here: callers invoke it inside an already-jitted layer scan
     (``models.transformer.decode_chunk_paged`` with ``kernel=True``)."""
     return paged_decode_chunk(q, k_pages, v_pages, page_table, pos,
-                              scale=scale, n_rep=n_rep,
+                              layer=layer, scale=scale, n_rep=n_rep,
                               interpret=not _on_tpu())

@@ -7,12 +7,13 @@ Two kernels:
    and optional sliding window.  This is the kernel behind
    ``layers.decode_attention(impl="pallas")``.
 
-2. ``paged_decode_chunk`` — attention for a T-token chunk over the
-   engine's paged pool ([n_pages, page, Hkv, Dh]) indexed through
-   per-sequence page tables, using PrefetchScalarGridSpec so the page table
-   is available to the BlockSpec index_map (the TPU-native equivalent of
-   vLLM's block tables: pages stage HBM->VMEM by table lookup, no gather
-   materialization).  ``paged_decode`` is its T=1 case.
+2. ``paged_decode_chunk`` — attention for a T-token chunk over one layer
+   of the engine's stacked paged pool ([L, n_pages, page, Hkv, Dh])
+   indexed through per-sequence page tables, using PrefetchScalarGridSpec
+   so the page table and the layer are available to the BlockSpec
+   index_map (the TPU-native equivalent of vLLM's block tables: pages
+   stage HBM->VMEM by table lookup, no gather materialization, and no
+   layer is sliced out of the pool).  ``paged_decode`` is its T=1 case.
 
 TPU layout: a K/V block is a whole page (or ring block) across ALL kv
 heads, ``(1, page, Hkv, Dh)``.  Its last two dimensions are the array's
@@ -145,9 +146,9 @@ def decode_ring(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
 
 
 # -------------------------------------------------------- paged chunk decode
-def _paged_chunk_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, scale: float, page: int,
-                        T: int, n_rep: int):
+def _paged_chunk_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref,
+                        o_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                        page: int, T: int, n_rep: int):
     """Grid: (B, max_pages).  q_ref: [1, Hkv, R, D] with R = T*n_rep query
     rows; row r belongs to chunk token ``r // n_rep`` at logical position
     ``pos[b] + r // n_rep``.  The chunk's own K/V is already scattered into
@@ -177,9 +178,16 @@ def _paged_chunk_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_chunk(q: jnp.ndarray, k_pages: jnp.ndarray,
                        v_pages: jnp.ndarray, page_table: jnp.ndarray,
-                       pos: jnp.ndarray, *, scale: float, n_rep: int,
+                       pos: jnp.ndarray, *, layer: int | jnp.ndarray,
+                       scale: float, n_rep: int,
                        interpret: bool = True) -> jnp.ndarray:
-    """Chunk-extended paged decode: q [B, T, H, D] over pool pages.
+    """Chunk-extended paged decode: q [B, T, H, D] over layer ``layer`` of
+    the stacked pool ``k_pages``/``v_pages`` [L, n_pages, page, Hkv, D].
+
+    The kernel only reads the pool, and only the pages the table names in
+    that layer: the caller keeps the whole pool where it lies and passes
+    the layer (a traced scalar inside a layer scan).  A caller holding one
+    layer's pool passes ``pool[None]`` and layer 0.
 
     ``pos`` [B] is each sequence's first chunk position; chunk token t
     queries positions <= pos+t.  The caller must have scattered the chunk's
@@ -188,42 +196,44 @@ def paged_decode_chunk(q: jnp.ndarray, k_pages: jnp.ndarray,
     engine reads row ``valid_len[b]-1`` only).  -> [B, T, H, D].
     """
     B, T, H, D = q.shape
-    n_pages, page, Hkv, _ = k_pages.shape
+    _L, n_pages, page, Hkv, _ = k_pages.shape
     max_pages = page_table.shape[1]
     R = T * n_rep
     # head h = kv*n_rep + rep (repeat_kv layout); row r = t*n_rep + rep
     qg = (q.reshape(B, T, Hkv, n_rep, D)
           .transpose(0, 2, 1, 3, 4).reshape(B, Hkv, R, D))
 
-    def kv_index(b, j, pt, pos_):
+    def kv_index(b, j, pt, pos_, lyr):
         # table lookup inside the index_map: the DMA fetches exactly the
-        # page this grid cell needs.  Cells past the chunk's last page
-        # repeat that page's index, so the pipeline skips their copy.
+        # page this grid cell needs, from the layer's slab of the pool.
+        # Cells past the chunk's last page repeat that page's index, so
+        # the pipeline skips their copy.
         last = jnp.maximum((pos_[b] + T - 1) // page, 0)
-        return (jnp.maximum(pt[b, jnp.minimum(j, last)], 0), 0, 0, 0)
+        return (lyr[0], jnp.maximum(pt[b, jnp.minimum(j, last)], 0), 0, 0, 0)
+
+    def q_index(b, j, pt, pos_, lyr):
+        return (b, 0, 0, 0)
 
     kernel = functools.partial(_paged_chunk_kernel, scale=scale, page=page,
                                T=T, n_rep=n_rep)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, max_pages),
             in_specs=[
-                pl.BlockSpec((1, Hkv, R, D),
-                             lambda b, j, pt, pos_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, page, Hkv, D), kv_index),
-                pl.BlockSpec((1, page, Hkv, D), kv_index),
+                pl.BlockSpec((1, Hkv, R, D), q_index),
+                pl.BlockSpec((None, 1, page, Hkv, D), kv_index),
+                pl.BlockSpec((None, 1, page, Hkv, D), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, Hkv, R, D),
-                                   lambda b, j, pt, pos_: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, Hkv, R, D), q_index),
             scratch_shapes=_scratch(Hkv, R, D),
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
         interpret=interpret,
         name="paged_decode_chunk",
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      qg, k_pages, v_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pages, v_pages)
     return (out.reshape(B, Hkv, T, n_rep, D)
             .transpose(0, 2, 1, 3, 4).reshape(B, T, H, D))
 
@@ -236,6 +246,7 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     page_table: [B, max_pages] (pool indices, -1 = unused);
     lengths: [B] valid tokens.  -> [B, H, D].  The T=1 chunk: the one
     query sits at position ``lengths - 1``."""
-    return paged_decode_chunk(q[:, None], k_pages, v_pages, page_table,
-                              lengths.astype(jnp.int32) - 1, scale=scale,
-                              n_rep=n_rep, interpret=interpret)[:, 0]
+    return paged_decode_chunk(q[:, None], k_pages[None], v_pages[None],
+                              page_table, lengths.astype(jnp.int32) - 1,
+                              layer=0, scale=scale, n_rep=n_rep,
+                              interpret=interpret)[:, 0]

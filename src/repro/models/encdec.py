@@ -302,7 +302,8 @@ def decode_chunk_paged(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         kp = kp.at[widx, off].set(k.astype(kp.dtype), mode="drop")
         vp = vp.at[widx, off].set(v.astype(vp.dtype), mode="drop")
         if kernel:
-            o = L.paged_chunk_attention(q, kp, vp, page_table, pos, cfg)
+            o = L.paged_chunk_attention(q, kp[None], vp[None], page_table,
+                                        pos, cfg, layer=0)
         else:
             o = L.chunk_decode_attention(q, gather(kp), gather(vp),
                                          positions, cfg)

@@ -426,19 +426,22 @@ def chunk_decode_attention_windowed(q: jnp.ndarray, cache_k: jnp.ndarray,
 
 def paged_chunk_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                           v_pages: jnp.ndarray, page_table: jnp.ndarray,
-                          pos: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """Pallas paged attention for a T-token chunk straight over the KV pool
-    (q [B,T,H,Dh]; pages [n_pages,P,Hkv,Dh]; the chunk's K/V must already
-    be scattered into the pages).
+                          pos: jnp.ndarray, cfg: ModelConfig, *,
+                          layer: int | jnp.ndarray) -> jnp.ndarray:
+    """Pallas paged attention for a T-token chunk straight over layer
+    ``layer`` of the stacked KV pool (q [B,T,H,Dh]; pages
+    [L,n_pages,P,Hkv,Dh]; the chunk's K/V must already be scattered into
+    the pages).
 
     The serving fast path behind ``decode_chunk_paged(kernel=True)``: no
-    dense gather is materialized — pages stage HBM->VMEM by table lookup.
+    dense gather is materialized and no layer is sliced out of the pool —
+    pages stage HBM->VMEM by table lookup.
     Numerics match :func:`chunk_decode_attention` to float tolerance but
     not bitwise (different softmax accumulation order), so the engine
     gates it behind its ``paged_kernel`` knob (auto-on on TPU only)."""
     from ..kernels.paged_attention.ops import paged_decode_chunk_attention
     return paged_decode_chunk_attention(
-        q, k_pages, v_pages, page_table, pos,
+        q, k_pages, v_pages, page_table, pos, layer=layer,
         scale=cfg.head_dim_ ** -0.5,
         n_rep=cfg.n_heads // cfg.n_kv_heads)
 

@@ -255,7 +255,12 @@ def decode_chunk_paged(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     ``chunk_decode_attention`` / ``_windowed`` math, so greedy outputs and
     cache bytes are bitwise identical to :func:`decode_chunk`.
     ``kernel=True`` instead runs the Pallas paged kernel over the tables
-    (no dense materialization; near-identical, not bitwise).  Windowed
+    (no dense materialization; near-identical, not bitwise).
+
+    The stacked pools [L, n_pages, P, Hkv, Dh] ride the layer scan's carry:
+    each layer scatters its new tokens' slots into them and attends its
+    own slab in place, so with donated pools no whole pool is copied and
+    no layer is sliced out or written back.  Windowed
     configs are only supported when ``max_seq <= sliding_window`` (ring
     slot == position, so the linear page layout matches the ring layout);
     the engine falls back to the dense path otherwise.
@@ -270,7 +275,7 @@ def decode_chunk_paged(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     W = cfg.sliding_window
     C = min(max_seq, W) if W else max_seq
 
-    _nl, n_pages, P, Hkv, Dh = k_pages.shape
+    n_layers, n_pages, P, Hkv, Dh = k_pages.shape
     maxp = page_table.shape[1]
     # position -> (page, offset) routing for the chunk's scatter writes
     pslot = jnp.minimum(positions // P, maxp - 1)              # [B,T]
@@ -280,46 +285,53 @@ def decode_chunk_paged(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     widx = jnp.where(oob, n_pages, page_of)                    # drop sentinel
     pt_c = jnp.maximum(page_table, 0)                          # [B,maxp]
 
-    def gather(pages):
-        # dense [B, C, Hkv, Dh] view — same length as the dense slot cache,
-        # so the attention HLO (and its reduction order) is identical
-        return pages[pt_c].reshape(B, maxp * P, Hkv, Dh)[:, :C]
+    def gather(pages, layer):
+        # dense [B, C, Hkv, Dh] view of one layer's tables, read straight
+        # from the stacked pool — same length as the dense slot cache, so
+        # the attention HLO (and its reduction order) is identical
+        return pages[layer, pt_c].reshape(B, maxp * P, Hkv, Dh)[:, :C]
 
-    def write(kp, vp, k, v):
+    def write(kp, vp, layer, k, v):
         with jax.named_scope("kv_write"):
-            return (kp.at[widx, off].set(k.astype(kp.dtype), mode="drop"),
-                    vp.at[widx, off].set(v.astype(vp.dtype), mode="drop"))
+            return (kp.at[layer, widx, off].set(k.astype(kp.dtype),
+                                                mode="drop"),
+                    vp.at[layer, widx, off].set(v.astype(vp.dtype),
+                                                mode="drop"))
 
     # named scopes (attention, attention/kv_write, mlp, unembed) label the
     # step's ops in a device profile
     def step(carry, xs):
-        x = carry
-        layer_p, kp, vp = xs
+        x, kp, vp = carry
+        layer_p, layer = xs
         with jax.named_scope("attention"):
             h = L.apply_norm(x, layer_p["attn_norm"], cfg)
             q, k, v = L.attention_qkv(h, layer_p["attn"], cfg, positions)
             if kernel:
-                kp, vp = write(kp, vp, k, v)
-                o = L.paged_chunk_attention(q, kp, vp, page_table, pos, cfg)
+                kp, vp = write(kp, vp, layer, k, v)
+                o = L.paged_chunk_attention(q, kp, vp, page_table, pos, cfg,
+                                            layer=layer)
             elif W:
                 # mirror decode_chunk's order exactly: attend the pre-write
                 # view + the chunk itself, then write
                 o = L.chunk_decode_attention_windowed(
-                    q, gather(kp), gather(vp), k, v, pos, valid_len,
-                    positions, cfg, window=W)
-                kp, vp = write(kp, vp, k, v)
+                    q, gather(kp, layer), gather(vp, layer), k, v, pos,
+                    valid_len, positions, cfg, window=W)
+                kp, vp = write(kp, vp, layer, k, v)
             else:
-                kp, vp = write(kp, vp, k, v)
-                o = L.chunk_decode_attention(q, gather(kp), gather(vp),
-                                             positions, cfg)
+                kp, vp = write(kp, vp, layer, k, v)
+                o = L.chunk_decode_attention(q, gather(kp, layer),
+                                             gather(vp, layer), positions,
+                                             cfg)
             x = x + jnp.einsum("bshk,hkd->bsd", o, layer_p["attn"]["wo"])
         with jax.named_scope("mlp"):
             h = L.apply_norm(x, layer_p["mlp_norm"], cfg)
             y, _aux = _ffn(h, layer_p, cfg, moe_impl)
             x = x + y
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (ks, vs) = jax.lax.scan(step, x, (params["layers"], k_pages, v_pages))
+    (x, ks, vs), _ = jax.lax.scan(
+        step, (x, k_pages, v_pages),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     with jax.named_scope("unembed"):
         x = L.apply_norm(x, params["final_norm"], cfg)
         logits = L.unembed(x, params["embed"], cfg)            # [B,T,V]

@@ -13,6 +13,7 @@ When hypothesis is not installed, the deterministic fallback shim
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -21,6 +22,7 @@ except ImportError:
 
 from repro.kernels.paged_attention.ops import (paged_decode_attention,
                                                paged_decode_chunk_attention)
+from repro.kernels.paged_attention.paged_attention import paged_decode_chunk
 from repro.kernels.paged_attention.ref import (paged_decode_chunk_ref,
                                                paged_decode_ref)
 
@@ -111,8 +113,8 @@ def test_paged_decode_chunk_fuzz(B, T, Hkv, n_rep, seed):
     q = jnp.asarray(rng.standard_normal((B, T, Hkv * n_rep, D)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((n_pages, page, Hkv, D)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal((n_pages, page, Hkv, D)), jnp.float32)
-    out = paged_decode_chunk_attention(q, kp, vp, pt, pos, scale=D ** -0.5,
-                                       n_rep=n_rep)
+    out = paged_decode_chunk_attention(q, kp[None], vp[None], pt, pos,
+                                       layer=0, scale=D ** -0.5, n_rep=n_rep)
     ref = paged_decode_chunk_ref(q, kp, vp, pt, pos, scale=D ** -0.5,
                                  n_rep=n_rep)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
@@ -133,15 +135,16 @@ def test_paged_chunk_permutation_invariance():
     q = jnp.asarray(rng.standard_normal((B, T, Hkv * n_rep, D)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((n_pages, page, Hkv, D)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal((n_pages, page, Hkv, D)), jnp.float32)
-    base = paged_decode_chunk_attention(q, kp, vp, pt, pos, scale=D ** -0.5,
-                                        n_rep=n_rep)
+    base = paged_decode_chunk_attention(q, kp[None], vp[None], pt, pos,
+                                        layer=0, scale=D ** -0.5, n_rep=n_rep)
     perm = rng.permutation(n_pages)
     inv = np.empty_like(perm)
     inv[perm] = np.arange(n_pages)                # new id of old page p
     pt_p = jnp.where(pt >= 0, jnp.asarray(inv)[jnp.maximum(pt, 0)], -1)
     relabeled = paged_decode_chunk_attention(
-        q, jnp.asarray(np.asarray(kp)[perm]), jnp.asarray(np.asarray(vp)[perm]),
-        pt_p.astype(jnp.int32), pos, scale=D ** -0.5, n_rep=n_rep)
+        q, jnp.asarray(np.asarray(kp)[perm])[None],
+        jnp.asarray(np.asarray(vp)[perm])[None], pt_p.astype(jnp.int32), pos,
+        layer=0, scale=D ** -0.5, n_rep=n_rep)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(relabeled))
 
 
@@ -157,8 +160,9 @@ def test_paged_chunk_ignores_garbage_beyond_pos():
     q = jnp.asarray(rng.standard_normal((B, T, Hkv * n_rep, D)), jnp.float32)
     kp = rng.standard_normal((n_pages, page, Hkv, D)).astype(np.float32)
     vp = rng.standard_normal((n_pages, page, Hkv, D)).astype(np.float32)
-    out = paged_decode_chunk_attention(q, jnp.asarray(kp), jnp.asarray(vp),
-                                       pt, pos, scale=D ** -0.5, n_rep=n_rep)
+    out = paged_decode_chunk_attention(q, jnp.asarray(kp)[None],
+                                       jnp.asarray(vp)[None], pt, pos,
+                                       layer=0, scale=D ** -0.5, n_rep=n_rep)
     # trash every byte beyond each row's visible range (and all unused pages)
     used = np.zeros((n_pages, page), bool)
     for b in range(B):
@@ -167,6 +171,57 @@ def test_paged_chunk_ignores_garbage_beyond_pos():
     kp2, vp2 = kp.copy(), vp.copy()
     kp2[~used] = 1e9
     vp2[~used] = -1e9
-    out2 = paged_decode_chunk_attention(q, jnp.asarray(kp2), jnp.asarray(vp2),
-                                        pt, pos, scale=D ** -0.5, n_rep=n_rep)
+    out2 = paged_decode_chunk_attention(q, jnp.asarray(kp2)[None],
+                                        jnp.asarray(vp2)[None], pt, pos,
+                                        layer=0, scale=D ** -0.5, n_rep=n_rep)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+# ------------------------------------------------ stacked [L, ...] pool
+L_STACK = 4
+
+
+def _stacked_case(T, seed=5):
+    """A [L, n_pages, page, Hkv, D] pool with a distinct slab per layer,
+    ragged rows and a permuted table."""
+    rng = np.random.default_rng(seed)
+    B, Hkv, n_rep, D, page, maxp = 2, 2, 2, 16, 4, 4
+    n_pages = B * maxp + 2
+    total, pt = _random_tables(rng, B, n_pages, maxp, page, maxp * page)
+    total = jnp.maximum(total, T)
+    pos = total - T
+    q = jnp.asarray(rng.standard_normal((B, T, Hkv * n_rep, D)), jnp.float32)
+    shape = (L_STACK, n_pages, page, Hkv, D)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    return q, kp, vp, pt, pos, dict(scale=D ** -0.5, n_rep=n_rep)
+
+
+@pytest.mark.parametrize("layer", [0, 2, L_STACK - 1])
+@pytest.mark.parametrize("T", [1, 2, 4, 8])
+def test_paged_decode_chunk_reads_one_layer_of_stacked_pool(T, layer):
+    """``layer=l`` over the stacked pool matches the oracle on ``pool[l]``,
+    and is bitwise the single-layer caller's ``pool[l][None]``, layer 0."""
+    q, kp, vp, pt, pos, kw = _stacked_case(T)
+    out = paged_decode_chunk(q, kp, vp, pt, pos, layer=layer, **kw)
+    ref = paged_decode_chunk_ref(q, kp[layer], vp[layer], pt, pos, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
+    one = paged_decode_chunk(q, kp[layer][None], vp[layer][None], pt, pos,
+                             layer=0, **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+
+
+@pytest.mark.parametrize("layer", [0, 2, L_STACK - 1])
+def test_paged_decode_chunk_never_reads_other_layers(layer):
+    """Garbage (NaN, huge values) in every other layer's slab leaves the
+    output bitwise unchanged: the kernel fetches only its layer's pages."""
+    q, kp, vp, pt, pos, kw = _stacked_case(4, seed=9)
+    base = paged_decode_chunk(q, kp, vp, pt, pos, layer=layer, **kw)
+    others = np.arange(L_STACK) != layer
+    kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
+    kp2[others] = np.nan
+    vp2[others] = 1e30
+    trashed = paged_decode_chunk(q, jnp.asarray(kp2), jnp.asarray(vp2), pt,
+                                 pos, layer=layer, **kw)
+    assert np.isfinite(np.asarray(trashed)).all()
+    np.testing.assert_array_equal(np.asarray(base), np.asarray(trashed))
